@@ -37,7 +37,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import collectives as C
 from repro.core import comms
-from repro.core.compat import make_mesh, shard_map
+from jax import make_mesh, shard_map
 from repro.kernels.collective_codec import ops as codec_ops
 
 mesh = make_mesh((2, 4), ("pod", "data"))
@@ -153,6 +153,7 @@ print(json.dumps(out))
 def run(report, tiny=False):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"   # a CPU benchmark: keep off the chip
     env["PYTHONPATH"] = SRC
     logs = "[12, 14]" if tiny else "[12, 14, 16, 18, 20]"
     prog = textwrap.dedent(_PROG) \

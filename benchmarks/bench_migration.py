@@ -112,6 +112,7 @@ def run(report, tiny=False):
         # the smoke run keeps the (fast, pure) simulator half only
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"   # a CPU benchmark: keep off the chip
         env["PYTHONPATH"] = SRC
         res = subprocess.run([sys.executable, "-c",
                               textwrap.dedent(_PROG)],

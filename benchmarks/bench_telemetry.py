@@ -138,6 +138,7 @@ def run(report, tiny=False):
     os.makedirs(RESULTS_DIR, exist_ok=True)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"   # a CPU benchmark: keep off the chip
     env["PYTHONPATH"] = SRC
     res = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(_PROG),

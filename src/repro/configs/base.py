@@ -79,6 +79,8 @@ class ArchConfig:
     scan_layers: bool = True         # scan over layers (False = unroll, for analysis)
     fsdp: bool = False               # ZeRO-3 style param sharding over data axis
     use_pallas_kernels: bool = False # TPU deployment path; CPU uses jnp reference
+    interpret_kernels: bool = False  # run those kernels in the Pallas
+                                     # interpreter (CPU tests); never implied
     sequence_parallel: bool = False  # shard sequence over data axis (long prefill)
     deploy: bool = False             # True: lax.scan inner loops (deployable
                                      # artifact, realistic memory); False:

@@ -28,8 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import comms, compat, telemetry
-from repro.core.compat import shard_map
+from repro.core import comms, telemetry
 from repro.kernels.collective_codec import ops as codec_ops
 
 
@@ -144,7 +143,7 @@ def ring_allreduce(vec, axis: str):
     """Bandwidth-optimal ring all-reduce via explicit collective-permutes
     (2*(n-1) steps: reduce-scatter ring + all-gather ring).  This is the
     ppermute mapping of the paper's p2p messaging layer."""
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return vec
     me = jax.lax.axis_index(axis)
@@ -187,12 +186,17 @@ def padded_size(tree, n_fast: int) -> int:
     return total + (-total) % n_fast
 
 
-def init_residual_buffer(mesh: Mesh, tree):
+def init_residual_buffer(mesh: Mesh, tree, mode: str = "compressed"):
     """Zero error-feedback buffer: (n_pods, padded_flat_size) f32, sharded
-    P('pod', 'data') so each chip holds its own scattered shard."""
+    P('pod', 'data') so each chip holds its own scattered shard.  Other
+    modes keep no residual: their placeholder is the (1, 1) per chip that
+    a train step hands back, so the step's input shape never changes
+    (one compile, not two)."""
     fast, slow = dp_axes(mesh)
     n_pods = mesh.shape[slow] if slow else 1
     n_total = n_pods * mesh.shape[fast]
+    if mode != "compressed":
+        return jnp.zeros((n_pods, mesh.shape[fast]), jnp.float32)
     return jnp.zeros((n_pods, padded_size(tree, n_total)), jnp.float32)
 
 
@@ -242,13 +246,13 @@ def build_tree_allreduce(mesh: Mesh, mode: str = "hierarchical",
     resid_spec = P(slow, fast) if slow else None
 
     def allreduce(tree, resid=None):
-        return shard_map(per_device, mesh=mesh,
-                         in_specs=(jax.tree.map(lambda _: spec_in, tree),
-                                   resid_spec),
-                         out_specs=(jax.tree.map(lambda _: spec_in, tree),
-                                    (resid_spec if mode == "compressed"
-                                     else None)),
-                         check_vma=False)(tree, resid)
+        return jax.shard_map(per_device, mesh=mesh,
+                             in_specs=(jax.tree.map(lambda _: spec_in, tree),
+                                       resid_spec),
+                             out_specs=(jax.tree.map(lambda _: spec_in, tree),
+                                        (resid_spec if mode == "compressed"
+                                         else None)),
+                             check_vma=False)(tree, resid)
 
     return allreduce
 

@@ -487,7 +487,7 @@ def _kernel_default(n_elems: int) -> bool:
 
 def fused_diff_apply(main, fork, child, op: str = "sum",
                      use_kernel: Optional[bool] = None,
-                     interpret: Optional[bool] = None
+                     interpret: bool = False
                      ) -> Tuple[np.ndarray, np.ndarray]:
     """One fused pass over a leaf: dirty detection against the fork
     snapshot + Table-3 merge into ``main``.  Returns

@@ -84,7 +84,8 @@ from repro.core.placement import (Allocation, CostModel, PlacementEngine,
 from repro.core.simulator import Job, Simulator, TraceResult
 
 # Relative per-chip speed by device generation, used to auto-detect a
-# mixed-generation pool (unknown kinds count as current-generation 1.0).
+# mixed-generation pool.  A mixed pool with a kind not listed here is an
+# error: guessing its speed would skew every placement on that fleet.
 DEVICE_KIND_SPEEDS = {
     "TPU v5": 1.0, "TPU v4": 0.75, "TPU v3": 0.45, "TPU v2": 0.25,
 }
@@ -95,14 +96,27 @@ def infer_host_speeds(devices: Sequence[Any], chips_per_host: int
     """Per-host speed factors for a mixed device pool, or ``None`` for a
     uniform pool (the homogeneous fast path).  Hosts follow the same
     consecutive-run layout as ``derive_capacities``; a host's speed is
-    the mean of its devices' generation factors."""
+    the mean of its devices' generation factors.  Raises ``ValueError``
+    for a mixed pool holding a kind ``DEVICE_KIND_SPEEDS`` does not know
+    (pass ``speeds`` to ``Fabric`` explicitly for such a fleet)."""
     kinds = [str(getattr(d, "device_kind", "")) for d in devices]
     if len(set(kinds)) <= 1:
         return None
+    return _host_speeds(kinds, derive_capacities(len(devices),
+                                                 chips_per_host))
+
+
+def _host_speeds(kinds: Sequence[str], caps: Sequence[int]) -> List[float]:
+    """Mean generation factor of each consecutive run of ``caps``
+    devices; an unlisted kind raises."""
+    unknown = sorted(set(kinds) - set(DEVICE_KIND_SPEEDS))
+    if unknown:
+        raise ValueError(f"mixed device pool with unknown device kinds "
+                         f"{unknown}; known: {sorted(DEVICE_KIND_SPEEDS)}")
     speeds, i = [], 0
-    for cap in derive_capacities(len(devices), chips_per_host):
-        factors = [DEVICE_KIND_SPEEDS.get(k, 1.0) for k in kinds[i:i + cap]]
-        speeds.append(float(np.mean(factors)))
+    for cap in caps:
+        speeds.append(float(np.mean([DEVICE_KIND_SPEEDS[k]
+                                     for k in kinds[i:i + cap]])))
         i += cap
     return speeds
 
@@ -673,22 +687,18 @@ class Fabric:
         speed relative to the incumbent generation."""
         caps = derive_capacities(len(devices), self.chips_per_host)
         kinds = [str(getattr(d, "device_kind", "")) for d in devices]
-        new_speeds, i = [], 0
-        for cap in caps:
-            new_speeds.append(float(np.mean(
-                [DEVICE_KIND_SPEEDS.get(k, 1.0)
-                 for k in kinds[i:i + cap]])))
-            i += cap
+        base_kinds = {str(getattr(d, "device_kind", ""))
+                      for d in self.devices}
+        speeds: Optional[List[float]] = None
         if self.engine.speeds is not None:
             # engine already carries absolute generation factors
-            speeds: Optional[List[float]] = new_speeds
-        else:
+            speeds = _host_speeds(kinds, caps)
+        elif set(kinds) != base_kinds or len(base_kinds) != 1:
             # uniform speedless fleet runs at relative 1.0; scale the
             # joiners against the incumbent generation and only
             # materialise speeds when they actually differ
-            base_kinds = {str(getattr(d, "device_kind", ""))
-                          for d in self.devices}
-            base = (DEVICE_KIND_SPEEDS.get(next(iter(base_kinds)), 1.0)
+            new_speeds = _host_speeds(kinds, caps)
+            base = (_host_speeds(sorted(base_kinds), [1])[0]
                     if len(base_kinds) == 1 else 1.0)
             rel = [s / base for s in new_speeds]
             speeds = (None if all(abs(r - 1.0) < 1e-9 for r in rel)

@@ -27,8 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 BLOCK_ROWS = 8  # chunk rows per block; the chunk width is the lane dim
 
@@ -67,7 +67,8 @@ def chunk_select(x, *, block_rows: int = BLOCK_ROWS,
         out_shape=[jax.ShapeDtypeStruct((k, 1), x.dtype),
                    jax.ShapeDtypeStruct((k, 1), jnp.int32),
                    jax.ShapeDtypeStruct((k, m), x.dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="chunk_select",
     )(x)

@@ -22,10 +22,6 @@ from repro.kernels.collective_codec.ref import chunk_select_ref
 KERNEL_MIN_SIZE = 1 << 16
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def codec_geometry(n: int, frac: float):
     """(k, m, padded) chunk geometry for an ``n``-element shard:
     ``k`` selected elements (chunk rows), chunk width ``m = ceil(n/k)``.
@@ -41,7 +37,7 @@ def codec_geometry(n: int, frac: float):
                    static_argnames=("frac", "use_kernel", "interpret"))
 def select_codec(vec, *, frac: float,
                  use_kernel: bool | None = None,
-                 interpret: bool | None = None):
+                 interpret: bool = False):
     """vec: flat (n,) -> (vals (k,), idx (k,) int32, resid (n,)).
 
     ``vals[i] = vec[idx[i]]`` is the largest-magnitude element of chunk
@@ -49,8 +45,6 @@ def select_codec(vec, *, frac: float,
     ``scatter(vals, idx) + resid == vec`` exactly (error feedback)."""
     n = vec.shape[0]
     k, m, padded = codec_geometry(n, frac)
-    if interpret is None:
-        interpret = _interpret_default()
     if use_kernel is None:
         # same routing as core.diffsync: the kernel is a TPU fast path;
         # CPU hosts stay on the vectorized jnp ref (running the kernel
@@ -62,9 +56,14 @@ def select_codec(vec, *, frac: float,
         x = jnp.pad(x, (0, padded - n))
     x = x.reshape(k, m)
     if use_kernel:
-        rows = _k.BLOCK_ROWS if k % _k.BLOCK_ROWS == 0 else 1
-        vals, col, resid = _k.chunk_select(x, block_rows=rows,
-                                           interpret=interpret)
+        # whole (BLOCK_ROWS, m) blocks only: a (1, m) block breaks the
+        # TPU's (8, 128) tiling.  Zero pad rows pick (0.0, col 0) and are
+        # sliced off, so the exact scatter + resid == vec invariant holds.
+        rows = -(-k // _k.BLOCK_ROWS) * _k.BLOCK_ROWS
+        if rows != k:
+            x = jnp.pad(x, ((0, rows - k), (0, 0)))
+        vals, col, resid = _k.chunk_select(x, interpret=interpret)
+        vals, col, resid = vals[:k], col[:k], resid[:k]
     else:
         vals, col, resid = chunk_select_ref(x)
     idx = jnp.arange(k, dtype=jnp.int32) * m + col[:, 0]

@@ -22,8 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 MERGE_OPS = ("sum", "subtract", "multiply", "divide", "overwrite")
 BLOCK_ROWS = 8  # chunks per block (rows); chunk width is the lane dim
@@ -94,7 +94,8 @@ def diff_merge(a0, b0, b1, *, op: str = "sum",
                    pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((n, c), a0.dtype),
                    jax.ShapeDtypeStruct((n, 1), jnp.bool_)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="diff_merge",
     )(a0, b0, b1)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
@@ -135,8 +134,9 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),    # running denom
             pltpu.VMEM((block_q, hd), jnp.float32),   # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

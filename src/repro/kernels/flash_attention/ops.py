@@ -2,8 +2,8 @@
 
 Accepts model-layout tensors (B, S, H, hd) / (B, S, KV, hd), transposes to
 the kernel's (B, H, S, hd) blocking layout, pads the head dim to a
-lane-aligned multiple of 128 when necessary (e.g. zamba2's hd=80), and
-selects interpret mode automatically off-TPU.
+lane-aligned multiple of 128 when necessary (e.g. zamba2's hd=80).
+Interpret mode (off-TPU testing) runs only when the caller asks for it.
 """
 from __future__ import annotations
 
@@ -15,17 +15,11 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import kernel as _k
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """q: (B,S,H,hd); k,v: (B,S,KV,hd) -> (B,S,H,hd)."""
-    if interpret is None:
-        interpret = _interpret_default()
     b, s, h, hd = q.shape
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)

@@ -7,7 +7,7 @@ inter-chunk recurrence never leaves the chip.  Within a chunk everything is
 (q x q) / (q x N) / (q x P) matmul work on the MXU.
 
 Per chunk (all f32 in VMEM):
-    cum     = cumsum(dt * a)                   (q,)
+    cum     = cumsum(dt * a)                   (q,)  masked row sum
     decay   = exp(cum_i - cum_j) masked i>=j   (q, q)
     y_intra = ((C B^T) .* decay .* dt_j) x
     y_inter = exp(cum) * (C . state)
@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core.compat import tpu_compiler_params
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref,
@@ -41,14 +39,18 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref,
     q = x.shape[0]
 
     da = dt * a                                  # (q, 1), negative
-    cum = jnp.cumsum(da, axis=0)                 # (q, 1)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    lower = ii >= jj
+    # inclusive cumsum as a masked lower-triangular row sum (Mosaic has
+    # no cumsum lowering)
+    cum = jnp.sum(jnp.where(lower, da.T, 0.0), axis=1,
+                  keepdims=True)                 # (q, 1)
     total = cum[-1:, :]                          # (1, 1)
 
     # within-chunk
     seg = cum - cum.T                            # (q, q): cum_i - cum_j
-    ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    decay = jnp.exp(jnp.where(ii >= jj, seg, -1e30))  # mask before exp
+    decay = jnp.exp(jnp.where(lower, seg, -1e30))  # mask before exp
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     m = scores * decay * dt.T                    # (q, q)
@@ -105,7 +107,8 @@ def ssd_scan(x, dt, a, b, c, *, chunk: int = 64, interpret: bool = False):
         out_shape=[jax.ShapeDtypeStruct((bs, h, l, p), x.dtype),
                    jax.ShapeDtypeStruct((bs, h, p, n), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt, a, b, c)

@@ -9,18 +9,12 @@ import jax.numpy as jnp
 from repro.kernels.mamba_scan import kernel as _k
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(x, dt, a, b, c, *, chunk: int = 64, interpret: bool | None = None):
+def ssd(x, dt, a, b, c, *, chunk: int = 64, interpret: bool = False):
     """Model layout: x (B,L,H,P), dt (B,L,H), a (H,), b/c (B,L,N).
 
     Returns y (B,L,H,P), final state (B,H,P,N) — same as
     ``models.ssm.ssd_chunked``."""
-    if interpret is None:
-        interpret = _interpret_default()
     xk = jnp.moveaxis(x, 2, 1)                       # (B,H,L,P)
     dtk = jnp.moveaxis(dt, 2, 1)[..., None]          # (B,H,L,1)
     ak = a[:, None, None]                            # (H,1,1)

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 NEG = -1e30
 
@@ -39,14 +38,18 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, h_ref,
     qq = q.shape[0]
 
     m_in = m_scr[0, 0]
-    cumf = jnp.cumsum(logf, axis=0)              # (q, 1)
-    total = cumf[-1, 0]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (qq, qq), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (qq, qq), 1)
+    lower = ii >= jj
+    # inclusive cumsum as a masked lower-triangular row sum (Mosaic has
+    # no cumsum lowering)
+    cumf = jnp.sum(jnp.where(lower, logf.T, 0.0), axis=1,
+                   keepdims=True)                # (q, 1)
+    total = cumf[qq - 1:, :]                     # (1, 1)
 
     # intra decay matrix (stabilised)
     dt = cumf - cumf.T + logi.T                  # (i, j)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (qq, qq), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (qq, qq), 1)
-    dt = jnp.where(ii >= jj, dt, NEG)
+    dt = jnp.where(lower, dt, NEG)
     m_intra = jnp.max(dt, axis=1, keepdims=True)          # (q, 1)
     b_inter = cumf + m_in                                 # (q, 1)
     m_comb = jnp.maximum(m_intra, b_inter)
@@ -79,7 +82,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, h_ref,
     n_scr[...] = carry * n_scr[...] + jax.lax.dot_general(
         wexp, k, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)      # (1, hd_k)
-    m_scr[0, 0] = m_out
+    m_scr[...] = m_out
 
     @pl.when(ci == nc - 1)
     def _finish():
@@ -118,7 +121,8 @@ def mlstm_scan(q, k, v, logi, logf, *, chunk: int = 128,
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32),
                         pltpu.VMEM((1, hd), jnp.float32),
                         pltpu.VMEM((1, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="mlstm_scan",
     )(q, k, v, logi, logf)

@@ -9,19 +9,13 @@ import jax.numpy as jnp
 from repro.kernels.mlstm import kernel as _k
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm(q, k, v, logi, logf, *, chunk: int = 128,
-          interpret: bool | None = None):
+          interpret: bool = False):
     """Model layout: q/k/v (B,L,H,hd); logi/logf (B,L,H).
 
     Returns h (B,L,H,hd) and state tuple (c (B,H,hd,hd), n (B,H,hd),
     m (B,H)) — same as ``models.xlstm.mlstm_chunked``."""
-    if interpret is None:
-        interpret = _interpret_default()
     move = lambda x: jnp.moveaxis(x, 2, 1)
     h, c, n, m = _k.mlstm_scan(
         move(q), move(k), move(v),

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 BLOCK_M = 128
 BLOCK_F = 512
@@ -80,7 +79,8 @@ def expert_ffn(x, w1, w2, w3, *, act: str = "silu",
                                lambda ee, mi, fi: (ee, mi, 0)),
         out_shape=jax.ShapeDtypeStruct((e, m, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="moe_expert_ffn",
     )(x, w1, w3, w2)

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # 512 host devices; never the chip
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -16,7 +17,7 @@ on the production mesh (single-pod 16x16 = 256 chips, multi-pod 2x16x16 =
 Results go to ``results/dryrun/<cell>.json``; ``--all`` fans cells out to
 subprocesses (one compile per process keeps XLA state isolated).
 
-NOTE: the XLA_FLAGS line above must run before ANY jax import — jax locks
+NOTE: the environment lines above must run before ANY jax import — jax locks
 the device count at first init.  Do not move it.
 """
 import argparse
@@ -144,7 +145,7 @@ def roofline(cost: Dict[str, float], coll: Dict[str, int],
 def _compile(cfg, shape, mesh):
     fn, args, in_sh = build_cell(cfg, shape, mesh)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
         lower_s = round(time.time() - t0, 1)
         t0 = time.time()

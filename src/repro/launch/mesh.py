@@ -7,11 +7,8 @@ while smoke tests must keep seeing 1 device.
 """
 from __future__ import annotations
 
-from typing import Tuple
-
+import jax
 from jax.sharding import Mesh
-
-from repro.core.compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,15 +17,11 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     Axes: ("data", "model") single pod; ("pod", "data", "model") multi-pod.
     The "pod" axis rides the slow inter-pod links (DCI/DCN); "data" and
     "model" ride intra-pod ICI — the hierarchy the paper's VM-leader
-    collectives exploit (DESIGN.md §5).  All axes are Auto-typed, which
-    is ``compat.make_mesh``'s behaviour on every jax version.
+    collectives exploit (DESIGN.md §5).  All axes are Auto-typed, so
+    placement follows the in/out shardings and sharding constraints.
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
-
-def make_host_mesh(shape: Tuple[int, ...],
-                   axes: Tuple[str, ...]) -> Mesh:
-    """Small mesh over host (CPU) devices for tests/benchmarks."""
-    return make_mesh(shape, axes)

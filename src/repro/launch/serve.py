@@ -1,5 +1,6 @@
 """Serving launcher: open-loop continuous batching vs the fixed-batch
-baseline on a reduced config.
+baseline, at the architecture's published width (``--reduced`` for the
+tiny same-family config the CPU can run).
 
 Requests arrive on their own (virtual) clock — Poisson, diurnal or
 bursty — and enter a ``ContinuousServeLoop`` slot as soon as one frees;
@@ -8,9 +9,13 @@ slowest batch loop, and ``--engine both`` reports the head-to-head.
 Latency percentiles are measured in virtual seconds (one decode step =
 ``--step-ms``); throughput additionally reports real wall time.
 
+On a TPU the model runs the Pallas kernels (flash attention, the MoE
+expert FFN, the recurrent scans); elsewhere it runs the jnp reference
+path.
+
 Example:
     PYTHONPATH=src python -m repro.launch.serve --arch xlstm-1.3b \
-        --engine both --arrival-regime burst --offered-load 0.6 \
+        --reduced --engine both --arrival-regime burst --offered-load 0.6 \
         --requests 24 --target-p99-ms 400
 """
 from __future__ import annotations
@@ -23,8 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.registry import ARCH_IDS, reduced_config
+from repro.configs.registry import ARCH_IDS, get_config, reduced_config
 from repro.core import telemetry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 from repro.runtime.admission import (ARRIVAL_REGIMES, request_stream,
                                      run_fixed_batch, run_open_loop)
@@ -52,9 +58,11 @@ def _extras_fns(cfg, seed: int):
     return one, batch
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="reduced same-family config (CPU-runnable)")
     ap.add_argument("--engine", default="continuous",
                     choices=["continuous", "fixed", "both"])
     ap.add_argument("--arrival-regime", default="poisson",
@@ -82,29 +90,47 @@ def main():
                          "event JSON (Perfetto-loadable) to PATH; the "
                          "metrics summary lands at PATH + "
                          "'.summary.json'")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    tel = (telemetry.enable() if args.emit_trace else telemetry.get())
 
-    cfg = reduced_config(args.arch)
+def load_model(args: argparse.Namespace, **overrides):
+    """(cfg, params): the architecture at published width (or reduced),
+    random weights from ``args.seed``, Pallas kernels on a TPU.
+    ``overrides`` replace config fields (e.g. interpreted kernels for a
+    CPU rehearsal)."""
+    cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
+    cfg = cfg.with_(**{"use_pallas_kernels": jax.default_backend() == "tpu",
+                       **overrides})
     key = jax.random.PRNGKey(args.seed)
     params = jax.jit(lambda k: tf.init_params(k, cfg))(key)
+    return cfg, params
+
+
+def make_stream(args: argparse.Namespace, cfg):
+    """The open-loop request stream ``args`` describes.  The fixed
+    baseline needs equal-length prompts; the continuous engine takes
+    the stream ragged."""
+    prompt_lens = ((max(1, args.prompt_len // 2), args.prompt_len)
+                   if args.engine == "continuous"
+                   else (args.prompt_len, args.prompt_len))
+    return request_stream(
+        args.requests, args.offered_load, args.seed,
+        regime=args.arrival_regime, vocab=cfg.vocab,
+        prompt_lens=prompt_lens,
+        max_new=(max(1, args.new_tokens // 2), args.new_tokens))
+
+
+def run(args: argparse.Namespace) -> dict:
+    """Serve the stream through the engine(s) ``args`` selects; returns
+    the report ``main`` prints."""
+    tel = (telemetry.enable() if args.emit_trace else telemetry.get())
+    cfg, params = load_model(args)
     batch = args.batch or args.slots
     step_s = args.step_ms / 1e3
     one_extra, batch_extra = _extras_fns(cfg, args.seed)
 
-    # the fixed baseline needs equal-length prompts; the continuous
-    # engine takes the stream ragged
-    prompt_lens = ((max(1, args.prompt_len // 2), args.prompt_len)
-                   if args.engine == "continuous"
-                   else (args.prompt_len, args.prompt_len))
-
     def stream():
-        return request_stream(
-            args.requests, args.offered_load, args.seed,
-            regime=args.arrival_regime, vocab=cfg.vocab,
-            prompt_lens=prompt_lens,
-            max_new=(max(1, args.new_tokens // 2), args.new_tokens))
+        return make_stream(args, cfg)
 
     out = {"arch": args.arch, "engine": args.engine,
            "arrival_regime": args.arrival_regime,
@@ -150,7 +176,12 @@ def main():
         tel.write_chrome_trace(args.emit_trace)
         tel.write_summary(args.emit_trace + ".summary.json")
         out["emit_trace"] = args.emit_trace
-    print(json.dumps(out, indent=1))
+    return out
+
+
+def main(argv=None):
+    enable_compile_cache()
+    print(json.dumps(run(parse_args(argv)), indent=1))
 
 
 if __name__ == "__main__":

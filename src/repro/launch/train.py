@@ -20,6 +20,7 @@ import time
 
 from repro.configs.registry import ARCH_IDS, get_config, reduced_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.train_loop import FaabricTrainRuntime, RuntimeConfig
 
@@ -46,6 +47,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     dcfg = DataConfig(vocab=cfg.vocab, seq_len=args.seq_len,
                       global_batch=args.global_batch, seed=args.seed)

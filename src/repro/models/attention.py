@@ -147,7 +147,8 @@ def attention(params, x, cfg, positions, *, causal=True, window=0,
             k = apply_rope(k, positions, cfg.rope_theta)
     if cfg.use_pallas_kernels and causal and kv_x is None:
         from repro.kernels.flash_attention import ops as fa_ops
-        out = fa_ops.flash_attention(q, k, v, causal=True, window=window)
+        out = fa_ops.flash_attention(q, k, v, causal=True, window=window,
+                                     interpret=cfg.interpret_kernels)
     elif causal and kv_x is None and q.shape[1] > BLOCK_Q:
         blocked = sdpa_blocked_scan if cfg.deploy else sdpa_blocked
         out = blocked(q, k, v, window=window)

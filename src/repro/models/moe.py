@@ -18,6 +18,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import dense_init
 
@@ -77,7 +78,8 @@ def _dispatch_tensors(gates, idx, cfg, capacity):
     dispatch = jnp.zeros((g, s, e, capacity), jnp.bfloat16)
     combine = jnp.zeros((g, s, e, capacity), jnp.bfloat16)
     for kk in range(k):                                      # per-slot
-        d_k = (jax.nn.one_hot(pos[:, :, kk], capacity, dtype=jnp.float32)
+        d_k = (jax.nn.one_hot(pos[:, :, kk].astype(jnp.int32), capacity,
+                              dtype=jnp.float32)
                * keep[:, :, kk, :, None])                    # (G,S,E,C)
         dispatch = dispatch + d_k.astype(jnp.bfloat16)
         combine = combine + (gates[:, :, kk, None, None]
@@ -100,15 +102,14 @@ def moe_ffn(params, x, cfg):
     combine = combine.astype(jnp.float32)
     # pin the E dim of dispatch/combine to the expert-parallel axis —
     # propagation otherwise replicates them and all-gathers per layer
-    # (§Perf #10; ~310 GB/device/step observed on granite before the pin)
-    if cfg.n_experts % 16 == 0:
-        try:
-            from jax.sharding import PartitionSpec as P
-            spec = P(None, None, "model", None)
-            dispatch = jax.lax.with_sharding_constraint(dispatch, spec)
-            combine = jax.lax.with_sharding_constraint(combine, spec)
-        except (ValueError, NameError, KeyError, TypeError):
-            pass  # no "model" axis in scope (CPU tests, gang runtime)
+    # (§Perf #10; ~310 GB/device/step observed on granite before the pin).
+    # Only a mesh set with ``jax.set_mesh`` that has a "model" axis has
+    # one to pin to; gang and serve meshes are data-parallel only.
+    if (cfg.n_experts % 16 == 0
+            and "model" in jax.sharding.get_abstract_mesh().axis_names):
+        spec = P(None, None, "model", None)
+        dispatch = jax.lax.with_sharding_constraint(dispatch, spec)
+        combine = jax.lax.with_sharding_constraint(combine, spec)
 
     # Gather expert inputs: (G,E,C,d)
     xe = jnp.einsum("gsec,gsd->gecd", dispatch, xg,
@@ -116,7 +117,8 @@ def moe_ffn(params, x, cfg):
     if cfg.use_pallas_kernels:
         from repro.kernels.moe_gmm import ops as gmm_ops
         ye = gmm_ops.expert_ffn(xe, params["w1"], params["w2"], params["w3"],
-                                act=cfg.act)
+                                act=cfg.act,
+                                interpret=cfg.interpret_kernels)
     else:
         h = jnp.einsum("gecd,edf->gecf", xe, params["w1"],
                        preferred_element_type=jnp.float32)

@@ -142,7 +142,8 @@ def mamba_forward(params, x, cfg) -> Tuple[jnp.ndarray, dict]:
     xh = xs.reshape(bs, l, h, p)
     if cfg.use_pallas_kernels:
         from repro.kernels.mamba_scan import ops as scan_ops
-        y, s_fin = scan_ops.ssd(xh, dt, a, b, c, chunk=cfg.ssm_chunk)
+        y, s_fin = scan_ops.ssd(xh, dt, a, b, c, chunk=cfg.ssm_chunk,
+                                interpret=cfg.interpret_kernels)
     else:
         y, s_fin = ssd_chunked(xh, dt, a, b, c, cfg.ssm_chunk)
     y = y + xh.astype(y.dtype) * params["d_skip"].astype(

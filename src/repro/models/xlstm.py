@@ -185,7 +185,8 @@ def mlstm_forward(params, x, cfg, state=None) -> Tuple[jnp.ndarray, dict]:
         st = (state["c"], state["n"], state["m"])
     if cfg.use_pallas_kernels:
         from repro.kernels.mlstm import ops as mlstm_ops
-        ht, st_fin = mlstm_ops.mlstm(q, k, v, logi, logf)
+        ht, st_fin = mlstm_ops.mlstm(q, k, v, logi, logf,
+                                     interpret=cfg.interpret_kernels)
     else:
         ht, st_fin = mlstm_chunked(q, k, v, logi, logf, st,
                                    use_scan=cfg.deploy)

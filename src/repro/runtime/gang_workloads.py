@@ -74,24 +74,24 @@ class TrainWorkload(GangWorkload):
                                                 global_batch=per * world)
             self._extras = extra_batch_specs(self.cfg,
                                              self.data_cfg.global_batch)
-        mode = resolve_sync_mode(
+        self._mode = resolve_sync_mode(
             self.sync_mode, handle,
             self.state["params"] if self.state is not None else None)
         self._step_fn = make_dp_train_step(
-            self.cfg, self.opt_cfg, handle.mesh, mode,
+            self.cfg, self.opt_cfg, handle.mesh, self._mode,
             self.compress_frac)
         if self.state is not None:
-            self.resid = coll.init_residual_buffer(handle.mesh,
-                                                   self.state["params"])
+            self.resid = coll.init_residual_buffer(
+                handle.mesh, self.state["params"], self._mode)
 
     def init_state(self, handle: GangHandle) -> None:
-        key = jax.random.PRNGKey(self.seed)
-        with jax.default_device(handle.devices[0]):
-            state = model_mod.init_train_state(key, self.cfg, self.opt_cfg)
-        rep = NamedSharding(handle.mesh, P())
-        self.state = jax.tree.map(lambda x: jax.device_put(x, rep), state)
-        self.resid = coll.init_residual_buffer(handle.mesh,
-                                               self.state["params"])
+        # built replicated on every gang device, not on one and copied
+        self.state = jax.jit(
+            lambda k: model_mod.init_train_state(k, self.cfg, self.opt_cfg),
+            out_shardings=NamedSharding(handle.mesh, P()),
+        )(jax.random.PRNGKey(self.seed))
+        self.resid = coll.init_residual_buffer(
+            handle.mesh, self.state["params"], self._mode)
 
     def run_step(self, handle: GangHandle) -> Dict[str, Any]:
         batch = dp.make_batch(self.data_cfg, self.steps_done, self._extras)

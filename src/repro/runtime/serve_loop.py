@@ -401,6 +401,27 @@ class ContinuousServeLoop:
                 jnp.zeros((self.slots,), jnp.int32))
 
     # ---- admission: ragged prefill spliced into one lane -------------------
+    def bucket(self, plen: int) -> int:
+        """Static prefill length a ``plen``-token prompt runs at: its
+        power-of-two bucket, or ``plen`` itself for recurrent configs."""
+        return plen if self._exact_prefill else min(self._size, _bucket(plen))
+
+    def lower(self, bucket: Optional[int] = None):
+        """The program ``admit`` runs for prompt ``bucket`` — or, with
+        None, the one ``decode_step`` runs — lowered for the current
+        placement without compiling; ``.as_text()`` shows, e.g., which
+        kernels are on the path."""
+        self._ensure_states()
+        if bucket is None:
+            pos = jnp.zeros((self.slots, 1), jnp.int32)
+            return self._serve.lower(self.params, self._states,
+                                     self._cur[:, None], pos)
+        batch = self._replicated({"tokens": jnp.zeros((1, bucket),
+                                                      jnp.int32)})
+        return self._admit_fn(bucket).lower(
+            self.params, self._states, self._cur, batch, jnp.int32(1),
+            jnp.int32(0))
+
     def _admit_fn(self, bucket: int):
         fn = self._admit_fns.get(bucket)
         if fn is not None:
@@ -442,8 +463,7 @@ class ContinuousServeLoop:
         assert 0 < plen <= self._size, \
             f"prompt ({plen}) must fit the decode buffer ({self._size})"
         self._ensure_states()
-        bucket = plen if self._exact_prefill \
-            else min(self._size, _bucket(plen))
+        bucket = self.bucket(plen)
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :plen] = prompt
         batch = self._replicated({"tokens": jnp.asarray(tokens),

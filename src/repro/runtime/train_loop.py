@@ -35,7 +35,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import ArchConfig
 from repro.core import collectives as coll
-from repro.core import compat
 from repro.core import control as ctl
 from repro.core import elastic as elastic_mod
 from repro.core.fabric import Fabric, GangHandle
@@ -115,7 +114,7 @@ def make_dp_train_step(cfg: ArchConfig, opt_cfg: adamw.AdamWConfig,
     resid_spec = P(slow, fast) if slow else P(None, fast)
 
     def train_step(state, batch, resid):
-        grads, metrics, new_resid = compat.shard_map(
+        grads, metrics, new_resid = jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(P(), jax.tree.map(
                 lambda _: dp_spec, batch), resid_spec),
@@ -218,10 +217,14 @@ class FaabricTrainRuntime:
         return jax.tree.map(lambda x: jax.device_put(x, s), batch)
 
     def init_state(self, seed: int = 0):
+        """Fresh train state, built replicated on every gang device (not
+        on one device and then copied)."""
         key = jax.random.PRNGKey(seed)
-        with jax.default_device(self.devices[0]):
-            state = model_mod.init_train_state(key, self.cfg, self.opt_cfg)
-        return jax.device_put(state, self._shardings(state))
+
+        def init(k):
+            return model_mod.init_train_state(k, self.cfg, self.opt_cfg)
+        shardings = self._shardings(jax.eval_shape(init, key))
+        return jax.jit(init, out_shardings=shardings)(key)
 
     # ---- control-point actions --------------------------------------------------
     def _elastic_probe(self, world: int) -> Optional[int]:
@@ -261,7 +264,8 @@ class FaabricTrainRuntime:
         carves the new sub-mesh under the configured policy (§2.1)."""
         state = self.handle.rescale(state, new_world)
         self._build(state)
-        resid = coll.init_residual_buffer(self.mesh, state["params"])
+        resid = coll.init_residual_buffer(self.mesh, state["params"],
+                                          self.sync_mode)
         return state, resid
 
     # ---- main loop ----------------------------------------------------------------
@@ -270,7 +274,8 @@ class FaabricTrainRuntime:
         if state is None:
             state = self.init_state(seed)
         self._build(state)
-        resid = coll.init_residual_buffer(self.mesh, state["params"])
+        resid = coll.init_residual_buffer(self.mesh, state["params"],
+                                          self.sync_mode)
         # checkpoint step semantics: "state before running step k"
         self.ckpt.save(0, state, blocking=True)
         step = 0
@@ -283,7 +288,8 @@ class FaabricTrainRuntime:
                 state, step = self._recover(state, step)
                 recoveries += 1
                 resid = coll.init_residual_buffer(self.mesh,
-                                                  state["params"])
+                                                  state["params"],
+                                                  self.sync_mode)
                 continue
             t0 = time.time()
             batch = dp.make_batch(self.data_cfg, step, self._extras)

@@ -217,7 +217,7 @@ def test_all_modes_agree_and_frac1_bit_exact():
     print(run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import collectives as C
-        from repro.core.compat import make_mesh
+        from jax import make_mesh
         mesh = make_mesh((2, 4), ("pod", "data"))
         tree = {"a": jax.random.normal(jax.random.PRNGKey(0), (8, 4, 33)),
                 "b": jax.random.normal(jax.random.PRNGKey(1), (8, 257))}
@@ -248,7 +248,7 @@ def test_slowlink_bytes_measured_from_hlo():
     print(run_sub("""
         import jax
         from repro.core import collectives as C
-        from repro.core.compat import make_mesh
+        from jax import make_mesh
         mesh = make_mesh((2, 4), ("pod", "data"))
         nbytes = 4096
         slow = {m: C.measure_schedule(mesh, m, nbytes, reps=1)
@@ -271,7 +271,7 @@ def test_ppermute_slowlink_counts_crossing_fraction():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.core import collectives as C
-        from repro.core.compat import make_mesh, shard_map
+        from jax import make_mesh, shard_map
         mesh = make_mesh((2, 4), ("pod", "data"))
         # ring over ALL 8 devices: 2 of 8 hops cross the pod boundary
         def body(v):
@@ -321,7 +321,7 @@ def test_compressed_error_feedback_converges_frac01():
     print(run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import collectives as C
-        from repro.core.compat import make_mesh
+        from jax import make_mesh
         mesh = make_mesh((2, 4), ("pod", "data"))
         tree = {"g": jax.random.normal(jax.random.PRNGKey(2), (8, 96))}
         f = jax.jit(C.build_tree_allreduce(mesh, mode="compressed",

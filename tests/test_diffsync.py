@@ -1,16 +1,10 @@
-"""Property-based tests (hypothesis, with example fallback) for the
-byte-wise diff protocol — Table 3 merge-op algebra and diff/apply
-invariants (paper §4)."""
+"""Property-based tests (hypothesis) for the byte-wise diff protocol —
+Table 3 merge-op algebra and diff/apply invariants (paper §4)."""
 import jax
 import numpy as np
+from hypothesis import given, settings, strategies as st
 
-import _hyp_compat as hc
 from repro.core import diffsync as D
-
-
-def _arr(n: int, seed: int) -> np.ndarray:
-    return np.random.default_rng(seed).normal(
-        size=n).astype(np.float32) + 2.0
 
 
 def _arrays(st):
@@ -21,12 +15,8 @@ def _arrays(st):
             st.integers(0, 2 ** 16)))
 
 
-_EXAMPLE_ARRAYS = [_arr(1, 0), _arr(7, 1), _arr(400, 2), _arr(4000, 3)]
-
-
-@hc.hyp_or_examples(
-    lambda st: (_arrays(st), st.integers(0, 2 ** 16)),
-    examples=[(a, s) for s, a in enumerate(_EXAMPLE_ARRAYS)])
+@settings(max_examples=40, deadline=None)
+@given(_arrays(st), st.integers(0, 2 ** 16))
 def test_sum_merge_is_grad_accumulation(a0, seed):
     """A1 = A0 + (B1 - B0): merging N children == summing their deltas."""
     rng = np.random.default_rng(seed)
@@ -41,7 +31,8 @@ def test_sum_merge_is_grad_accumulation(a0, seed):
     np.testing.assert_allclose(main, a0 + sum(deltas), atol=1e-5)
 
 
-@hc.hyp_or_examples(lambda st: (_arrays(st),), examples=_EXAMPLE_ARRAYS)
+@settings(max_examples=40, deadline=None)
+@given(_arrays(st))
 def test_overwrite_roundtrip(a0):
     """diff(old, new) applied to old reproduces new exactly."""
     rng = np.random.default_rng(1)
@@ -52,17 +43,16 @@ def test_overwrite_roundtrip(a0):
     np.testing.assert_array_equal(D.apply_leaf(a0, d), new)
 
 
-@hc.hyp_or_examples(lambda st: (_arrays(st),), examples=_EXAMPLE_ARRAYS)
+@settings(max_examples=40, deadline=None)
+@given(_arrays(st))
 def test_clean_state_empty_diff(a0):
     d = D.diff_leaf(a0, a0.copy())
     assert d.idx.size == 0
     np.testing.assert_array_equal(D.apply_leaf(a0, d), a0)
 
 
-@hc.hyp_or_examples(
-    lambda st: (_arrays(st), st.sampled_from(["sum", "subtract"])),
-    examples=[(_EXAMPLE_ARRAYS[1], "sum"), (_EXAMPLE_ARRAYS[2], "subtract"),
-              (_EXAMPLE_ARRAYS[3], "sum")])
+@settings(max_examples=40, deadline=None)
+@given(_arrays(st), st.sampled_from(["sum", "subtract"]))
 def test_sum_subtract_inverse(a0, op):
     """subtract(A0, B0, B1) == sum(A0, B1, B0): Table 3 algebra."""
     rng = np.random.default_rng(2)
@@ -73,8 +63,8 @@ def test_sum_subtract_inverse(a0, op):
     np.testing.assert_allclose(via_sub + via_sum, 2 * a0, atol=1e-4)
 
 
-@hc.hyp_or_examples(lambda st: (st.integers(0, 2 ** 16),),
-                    examples=[0, 7, 12345], max_examples=30)
+@settings(max_examples=30, deadline=None)
+@given(st.integers(0, 2 ** 16))
 def test_multiply_merge(seed):
     rng = np.random.default_rng(seed)
     a0 = rng.uniform(1, 2, 2048).astype(np.float32)
@@ -85,8 +75,8 @@ def test_multiply_merge(seed):
     np.testing.assert_allclose(merged, a0 * scale, rtol=1e-4)
 
 
-@hc.hyp_or_examples(lambda st: (st.integers(0, 2 ** 16),),
-                    examples=[1, 42, 65535], max_examples=20)
+@settings(max_examples=20, deadline=None)
+@given(st.integers(0, 2 ** 16))
 def test_tree_diff_only_ships_dirty_bytes(seed):
     rng = np.random.default_rng(seed)
     tree = {"a": rng.normal(size=(64, 64)).astype(np.float32),
@@ -132,12 +122,10 @@ def _dirty_pair(n, dtype, seed, frac=9):
     return b0, b1
 
 
-@hc.hyp_or_examples(
-    lambda st: (st.sampled_from(list(D.MERGE_OPS)),
-                st.sampled_from(list(_PARITY_SIZES)),
-                st.integers(0, 2 ** 16)),
-    examples=[(op, n, i) for i, (op, n) in enumerate(
-        (op, n) for op in D.MERGE_OPS for n in (7, 1024, 4000))])
+@settings(max_examples=40, deadline=None)
+@given(st.sampled_from(list(D.MERGE_OPS)),
+       st.sampled_from(list(_PARITY_SIZES)),
+       st.integers(0, 2 ** 16))
 def test_parity_with_reference_float(op, n, seed):
     """diff_leaf/apply_leaf match reference_* bit-for-bit on floats."""
     rng = np.random.default_rng(seed)
@@ -178,12 +166,10 @@ def _dtypes():
     return [np.float32, np.float64, np.int32, ml_dtypes.bfloat16]
 
 
-@hc.hyp_or_examples(
-    lambda st: (st.sampled_from(_dtypes()),
-                st.sampled_from([1, 13, 1023, 1025, 5000]),
-                st.integers(0, 2 ** 16)),
-    examples=[(dt, n, i) for i, (dt, n) in enumerate(
-        (dt, n) for dt in _dtypes() for n in (13, 1025, 5000))])
+@settings(max_examples=40, deadline=None)
+@given(st.sampled_from(_dtypes()),
+       st.sampled_from([1, 13, 1023, 1025, 5000]),
+       st.integers(0, 2 ** 16))
 def test_overwrite_roundtrip_dtypes_ragged(dtype, n, seed):
     """diff -> apply reproduces the child exactly for every dtype,
     including ragged non-multiple-of-CHUNK shapes."""
@@ -203,10 +189,9 @@ def test_overwrite_roundtrip_dtypes_ragged(dtype, n, seed):
     np.testing.assert_array_equal(got, new)
 
 
-@hc.hyp_or_examples(
-    lambda st: (st.sampled_from(list(D.MERGE_OPS)),
-                st.integers(0, 2 ** 16)),
-    examples=[(op, i) for i, op in enumerate(D.MERGE_OPS)])
+@settings(max_examples=40, deadline=None)
+@given(st.sampled_from(list(D.MERGE_OPS)),
+       st.integers(0, 2 ** 16))
 def test_all_ops_roundtrip_ragged(op, seed):
     """Five-op merge algebra on a ragged leaf: merged value matches the
     scalarwise oracle applied to the dirty chunks."""
@@ -261,10 +246,9 @@ def test_apply_leaf_empty_diff_passthrough_and_inplace():
 # ---------------------------------------------------------------------------
 # apply_many: N-way merge == sequential application
 # ---------------------------------------------------------------------------
-@hc.hyp_or_examples(
-    lambda st: (st.sampled_from(["sum", "overwrite", "multiply"]),
-                st.integers(0, 2 ** 16)),
-    examples=[("sum", 0), ("overwrite", 1), ("multiply", 2), ("sum", 3)])
+@settings(max_examples=40, deadline=None)
+@given(st.sampled_from(["sum", "overwrite", "multiply"]),
+       st.integers(0, 2 ** 16))
 def test_apply_many_matches_sequential(op, seed):
     n = 9000
     rng = np.random.default_rng(seed)
@@ -349,7 +333,7 @@ def test_tracked_fork_read_through():
 # dense_merge dtype preservation (satellite 1)
 # ---------------------------------------------------------------------------
 def test_dense_merge_preserves_f64_precision():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         import jax.numpy as jnp
         old = np.full(2048, 1.0, dtype=np.float64)

@@ -27,7 +27,7 @@ def test_collective_modes_agree():
     print(run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import collectives as C
-        from repro.core.compat import make_mesh
+        from jax import make_mesh
         mesh = make_mesh((2, 4), ("pod", "data"))
         tree = {"a": jax.random.normal(jax.random.PRNGKey(0), (8, 4, 3)),
                 "b": jax.random.normal(jax.random.PRNGKey(1), (8, 7))}
@@ -47,7 +47,7 @@ def test_compressed_allreduce_error_feedback_converges():
     print(run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import collectives as C
-        from repro.core.compat import make_mesh
+        from jax import make_mesh
         mesh = make_mesh((2, 4), ("pod", "data"))
         tree = {"g": jax.random.normal(jax.random.PRNGKey(0), (8, 64))}
         f = jax.jit(C.build_tree_allreduce(mesh, mode="compressed",

@@ -87,6 +87,23 @@ def test_infer_host_speeds_uniform_pool_is_homogeneous():
     assert speeds == [0.75, 0.25, 0.75]     # ragged last host included
 
 
+def test_infer_host_speeds_unknown_kind_in_mixed_pool_raises():
+    from repro.core.fabric import Fabric, infer_host_speeds
+
+    class Dev:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    # a uniform pool of a kind the table lacks is still homogeneous
+    assert infer_host_speeds([Dev("TPU v5 lite")] * 4, 4) is None
+    # mixed with an unknown kind: no silent 1.0 for the stranger
+    with pytest.raises(ValueError, match="unknown device kinds"):
+        infer_host_speeds([Dev("TPU v4")] * 2 + [Dev("TPU v5 lite")] * 2, 2)
+    fab = Fabric(devices=[Dev("TPU v4")] * 2, chips_per_host=2)
+    with pytest.raises(ValueError, match="unknown device kinds"):
+        fab.join_hosts([Dev("TPU v5 lite")] * 2)
+
+
 def test_join_hosts_infers_generation_speeds():
     from repro.core.fabric import Fabric
 

@@ -104,7 +104,7 @@ def test_diff_merge_int32_exact(op):
 def test_diff_merge_leaf_f64_keeps_precision():
     """f64 leaves keep full precision through the kernel path (the old
     blanket float32 cast flattened sub-f32 deltas)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     from repro.kernels.diff_merge import ops as O
     with enable_x64():
         a0 = jnp.full((3000,), 1.0, dtype=jnp.float64)
@@ -165,6 +165,25 @@ def test_moe_gmm(e, m, d, ff, act):
                                atol=1e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("g,c", [(1, 5), (1, 10), (2, 10), (4, 160)])
+def test_expert_ffn_ops_pads_rows_to_sublane_tiles(g, c):
+    """M = G*C rows not a multiple of 8 pad to whole blocks (the TPU
+    tiling) and the padding never reaches the output."""
+    from repro.kernels.moe_gmm import ops as O, ref as R
+    e, d, ff = 4, 64, 256
+    xe = jax.random.normal(sub(35), (g, e, c, d)) * 0.5
+    w1 = jax.random.normal(sub(36), (e, d, ff)) * 0.05
+    w2 = jax.random.normal(sub(37), (e, ff, d)) * 0.05
+    w3 = jax.random.normal(sub(38), (e, d, ff)) * 0.05
+    out = O.expert_ffn(xe, w1, w2, w3, interpret=True)
+    x = jnp.swapaxes(xe, 0, 1).reshape(e, g * c, d)
+    expect = R.expert_ffn_ref(x, w1, w2, w3, act="silu")
+    expect = jnp.swapaxes(expect.reshape(e, g, c, d), 0, 1)
+    assert out.shape == xe.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               atol=1e-5, rtol=1e-4)
+
+
 def test_moe_gmm_matches_model_path():
     """Kernel path through moe_ffn == reference einsum path."""
     from repro.configs.registry import reduced_config
@@ -174,7 +193,7 @@ def test_moe_gmm_matches_model_path():
     x = jax.random.normal(sub(14), (2, 64, cfg.d_model))
     y_ref, aux_ref = jax.jit(
         lambda p, x: moe_mod.moe_ffn(p, x, cfg))(params, x)
-    cfg_k = cfg.with_(use_pallas_kernels=True)
+    cfg_k = cfg.with_(use_pallas_kernels=True, interpret_kernels=True)
     y_k, aux_k = jax.jit(
         lambda p, x: moe_mod.moe_ffn(p, x, cfg_k))(params, x)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_k),
@@ -281,7 +300,8 @@ def test_collective_codec_kernel_matches_ref(k, m):
 
 
 @pytest.mark.parametrize("n,frac", [(1000, 0.1), (7, 0.3), (4096, 0.05),
-                                    (100, 1.0), (1, 0.5), (1 << 17, 0.05)])
+                                    (100, 1.0), (1, 0.5), (1 << 17, 0.05),
+                                    ((1 << 16) + 3, 0.01)])
 def test_collective_codec_roundtrip_exact(n, frac):
     from repro.kernels.collective_codec import ops as O
     vec = jax.random.normal(sub(41), (n,))
@@ -291,6 +311,8 @@ def test_collective_codec_roundtrip_exact(n, frac):
         else {}
     vals, idx, resid = O.select_codec(vec, frac=frac, **kw)
     k, m, _ = O.codec_geometry(n, frac)
+    # both kernel-path sizes have k % 8 != 0: the kernel sees whole
+    # 8-row blocks padded past k, which must not leak into the message
     assert vals.shape == (k,) and idx.shape == (k,)
     assert idx.dtype == jnp.int32
     recon = jnp.zeros((n,)).at[idx].add(vals) + resid

@@ -1,25 +1,16 @@
-"""Scheduler allocation invariants (property-based, with example fallback)
-and simulator reproduction of the paper's qualitative results
-(Fig 10/11/14)."""
+"""Scheduler allocation invariants (property-based) and simulator
+reproduction of the paper's qualitative results (Fig 10/11/14)."""
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
-import _hyp_compat as hc
 from repro.core import simulator as S
 from repro.core.scheduler import ClusterState
 
 
-@hc.hyp_or_examples(
-    lambda st: (st.lists(st.integers(1, 20), min_size=1, max_size=30),
-                st.integers(2, 8), st.integers(4, 16)),
-    examples=[
-        ([1] * 30, 2, 4),
-        ([20, 13, 7, 1, 5], 8, 16),
-        ([8, 8, 8, 8, 8], 4, 5),
-        ([3], 5, 9),
-        (list(range(1, 21)), 6, 10),
-        ([16, 16, 16], 2, 4),
-    ])
+@settings(max_examples=40, deadline=None)
+@given(st.lists(st.integers(1, 20), min_size=1, max_size=30),
+       st.integers(2, 8), st.integers(4, 16))
 def test_granular_alloc_conserves_chips(sizes, chips, hosts):
     cs = ClusterState(hosts, chips)
     allocs = []
@@ -35,10 +26,8 @@ def test_granular_alloc_conserves_chips(sizes, chips, hosts):
     assert cs.idle_chips() == cs.total_chips
 
 
-@hc.hyp_or_examples(
-    lambda st: (st.integers(1, 64), st.integers(1, 8)),
-    examples=[(1, 1), (7, 2), (64, 1), (64, 8), (13, 4), (33, 8),
-              (8, 3), (5, 5)])
+@settings(max_examples=40, deadline=None)
+@given(st.integers(1, 64), st.integers(1, 8))
 def test_slice_alloc_wastes_fragmentation(n, k):
     """Slice allocation rounds up to whole slices — the paper's
     fragmentation waste."""
