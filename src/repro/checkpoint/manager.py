@@ -74,6 +74,20 @@ class CheckpointManager:
     # ---- save ---------------------------------------------------------------
     def save(self, step: int, state, blocking: bool = True) -> Dict[str, Any]:
         """Checkpoint the state pytree at ``step``."""
+        tel = telemetry.get()
+        with tel.span("ckpt.save", track=f"gang:{self.job_id}",
+                      step=step) as span:
+            stat = self._save(step, state, blocking)
+            span.set(kind=stat["kind"], bytes=stat["bytes"],
+                     full_bytes=stat["full_bytes"])
+        if tel.enabled:
+            tel.count(f"ckpt.save.{stat['kind']}")
+            tel.count("ckpt.save.bytes", stat["bytes"])
+            tel.observe("ckpt.device_to_host_s", stat["device_to_host_s"])
+            tel.gauge("ckpt.chain_len", self._chain_len)
+        return stat
+
+    def _save(self, step: int, state, blocking: bool) -> Dict[str, Any]:
         t0 = time.time()
         snap = snap_mod.take(self.job_id, step, state)
         copy_s = time.time() - t0
@@ -143,17 +157,6 @@ class CheckpointManager:
                 "full_bytes": snap.nbytes,
                 "device_to_host_s": copy_s}
         self.stats.append(stat)
-        tel = telemetry.get()
-        if tel.enabled:
-            tel.count(f"ckpt.save.{payload['kind']}")
-            tel.count("ckpt.save.bytes", nbytes)
-            tel.observe("ckpt.device_to_host_s", copy_s)
-            tel.gauge("ckpt.chain_len", self._chain_len)
-            p1 = time.perf_counter()
-            tel.span_at("ckpt.save", p1 - (time.time() - t0), p1,
-                        track=f"gang:{self.job_id}", clock="wall",
-                        step=step, kind=payload["kind"], bytes=nbytes,
-                        full_bytes=snap.nbytes)
         return stat
 
     def wait(self) -> None:
@@ -187,6 +190,16 @@ class CheckpointManager:
         """Load state at ``step`` (default: latest).  Diff checkpoints are
         replayed on top of their base full checkpoint."""
         t0 = time.perf_counter()
+        tel = telemetry.get()
+        with tel.span("ckpt.restore", track=f"gang:{self.job_id}") as span:
+            restored, payload = self._restore(step, shardings)
+            span.set(step=payload["step"], kind=payload["kind"])
+        if tel.enabled:
+            tel.count("ckpt.restores")
+            tel.observe("ckpt.restore_s", time.perf_counter() - t0)
+        return restored, payload["step"]
+
+    def _restore(self, step: Optional[int], shardings):
         self.wait()
         entries = self._manifest()
         if not entries:
@@ -230,13 +243,4 @@ class CheckpointManager:
                                         payload["diffs"])
         snap = snap_mod.Snapshot(self.job_id, payload["step"], state,
                                  fingerprint=payload["fingerprint"])
-        restored = snap_mod.restore(snap, shardings)
-        tel = telemetry.get()
-        if tel.enabled:
-            t1 = time.perf_counter()
-            tel.count("ckpt.restores")
-            tel.observe("ckpt.restore_s", t1 - t0)
-            tel.span_at("ckpt.restore", t0, t1,
-                        track=f"gang:{self.job_id}", clock="wall",
-                        step=payload["step"], kind=payload["kind"])
-        return restored, payload["step"]
+        return snap_mod.restore(snap, shardings), payload

@@ -152,26 +152,25 @@ class GangWorkload:
 
 
 def _gang_span(name: str):
-    """Wall-clock lifecycle span around a GangHandle method — zero-cost
-    (plain call-through) under the default no-op telemetry recorder."""
+    """Wall-clock lifecycle span around a GangHandle method (also in the
+    profiler's trace while a session is active) — zero-cost (plain
+    call-through) when nothing records."""
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(self, *args, **kwargs):
+            if not telemetry.active():
+                return fn(self, *args, **kwargs)
             tel = telemetry.get()
-            if not tel.enabled:
-                return fn(self, *args, **kwargs)
-            t0 = time.perf_counter()
-            try:
-                return fn(self, *args, **kwargs)
-            finally:
-                pl = (self.alloc.placement
-                      if self.alloc is not None else [])
-                tel.count(f"gang.{name}")
-                tel.span_at(f"gang.{name}", t0, time.perf_counter(),
-                            track=f"gang:{self.job_id}", clock="wall",
-                            job=self.job_id, kind=self.kind,
-                            chips=len(self.devices),
-                            hosts=len({h for h, _ in pl}))
+            with tel.span(f"gang.{name}", track=f"gang:{self.job_id}",
+                          job=self.job_id, kind=self.kind) as span:
+                try:
+                    return fn(self, *args, **kwargs)
+                finally:
+                    pl = (self.alloc.placement
+                          if self.alloc is not None else [])
+                    tel.count(f"gang.{name}")
+                    span.set(chips=len(self.devices),
+                             hosts=len({h for h, _ in pl}))
         return wrapper
     return deco
 
@@ -388,37 +387,35 @@ class GangHandle:
         actually dirtied.  ``fail`` replays base+deltas and proves the
         chain bit-exact against the recorded fingerprint."""
         tel = telemetry.get()
-        t_ckpt = time.perf_counter() if tel.enabled else 0.0
-        snap = snap_mod.take(self.job_id, step, state)
-        prev = self.last_checkpoint
-        rebase = (self._ckpt_base is None
-                  or len(self._ckpt_deltas) >= self.ckpt_rebase_every - 1
-                  or prev is None
-                  or not self._same_layout(prev.state, snap.state))
-        if rebase:
-            self._ckpt_base = snap
-            self._ckpt_deltas = []
-            ckpt_kind, shipped = "full", snap.nbytes
-        else:
-            diffs = diffsync.diff_tree(prev.state, snap.state,
-                                       op="overwrite")
-            self._ckpt_deltas.append(
-                {"step": step, "diffs": diffs,
-                 "fingerprint": snap.fingerprint})
-            ckpt_kind, shipped = "delta", diffsync.diff_nbytes(diffs)
-        self.last_checkpoint = snap
-        self.ckpt_stats.append({"step": step, "kind": ckpt_kind,
-                                "bytes": shipped,
-                                "full_bytes": snap.nbytes})
+        with tel.span("ckpt.save", track=f"gang:{self.job_id}",
+                      step=step) as span:
+            snap = snap_mod.take(self.job_id, step, state)
+            prev = self.last_checkpoint
+            rebase = (self._ckpt_base is None
+                      or len(self._ckpt_deltas) >= self.ckpt_rebase_every - 1
+                      or prev is None
+                      or not self._same_layout(prev.state, snap.state))
+            if rebase:
+                self._ckpt_base = snap
+                self._ckpt_deltas = []
+                ckpt_kind, shipped = "full", snap.nbytes
+            else:
+                diffs = diffsync.diff_tree(prev.state, snap.state,
+                                           op="overwrite")
+                self._ckpt_deltas.append(
+                    {"step": step, "diffs": diffs,
+                     "fingerprint": snap.fingerprint})
+                ckpt_kind, shipped = "delta", diffsync.diff_nbytes(diffs)
+            self.last_checkpoint = snap
+            self.ckpt_stats.append({"step": step, "kind": ckpt_kind,
+                                    "bytes": shipped,
+                                    "full_bytes": snap.nbytes})
+            span.set(kind=ckpt_kind, bytes=shipped, full_bytes=snap.nbytes)
         if tel.enabled:
             tel.count(f"ckpt.{ckpt_kind}")
             tel.count("ckpt.bytes_shipped", shipped)
             tel.count("ckpt.bytes_full", snap.nbytes)
             tel.gauge("ckpt.chain_len", len(self._ckpt_deltas))
-            tel.span_at("ckpt.save", t_ckpt, time.perf_counter(),
-                        track=f"gang:{self.job_id}", clock="wall",
-                        step=step, kind=ckpt_kind, bytes=shipped,
-                        full_bytes=snap.nbytes)
         self.epoch_log.append(
             {"kind": "checkpoint", "step": step,
              "fingerprint": snap.fingerprint,

@@ -9,6 +9,15 @@ whose every method returns immediately, so instrumented call sites are
 zero-cost and all pinned traces stay bit-identical until a caller
 explicitly installs a live recorder with :func:`enable` / :func:`recording`.
 
+One span API, two sinks.  ``with get().span(name, track, **attrs):``
+records into the live recorder when one is installed, and enters a
+``jax.profiler.TraceAnnotation`` whenever a profiler session is active,
+so the span lands in the profiler's trace on the clock of the device
+ops.  With neither, ``span`` returns a shared null context: no
+annotation is built.  Call sites that compute attributes gate that work
+on :func:`active`.  A span written after the fact (``span_at``) reaches
+the recorder only.
+
 Two clocks share one span schema:
 
 * ``clock="wall"`` — real elapsed time (``time.perf_counter``), used by
@@ -34,19 +43,21 @@ per (host-kind, job-kind); :meth:`feed_cost_model` pushes them into
 ``CostModel.observe_step`` so the self-calibration loop has a data source.
 
 The module imports nothing from the rest of ``repro`` (Action objects are
-duck-typed via ``.kind`` / ``.payload``), so any layer may import it.
+duck-typed via ``.kind`` / ``.payload``), so any layer may import it; it
+imports ``jax`` only once something else has.
 """
 from __future__ import annotations
 
 import bisect
 import difflib
 import json
+import sys
 import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = [
-    "Telemetry", "get", "enable", "disable", "recording",
-    "diff_traces", "spans_from_actions",
+    "Telemetry", "get", "enable", "disable", "recording", "active",
+    "profiling", "diff_traces", "spans_from_actions",
 ]
 
 # Fixed histogram bucket bounds: 1 µs .. 100 s, four per decade.  Fixed
@@ -107,26 +118,66 @@ class _Histogram:
         }
 
 
+#: ``jax.profiler.TraceAnnotation``, looked up once ``jax`` is imported:
+#: before then no profiler session can be active
+_Annotation = None
+
+
+def _annotation_cls():
+    global _Annotation
+    if _Annotation is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _Annotation = TraceAnnotation
+    return _Annotation
+
+
+def profiling() -> bool:
+    """True while a JAX profiler session is recording."""
+    cls = _Annotation or _annotation_cls()
+    return cls is not None and cls.is_enabled()
+
+
+def active() -> bool:
+    """True when a span is recorded somewhere: a live recorder is
+    installed or a profiler session is active.  Call sites gate the
+    construction of span attributes on it."""
+    return _current.enabled or profiling()
+
+
 class _SpanCtx:
-    """Context manager recording one wall-clock span on exit."""
+    """One wall-clock span: into the recorder ``tel`` on exit (None for
+    none) and, while a profiler session is active, into its trace."""
 
-    __slots__ = ("_tel", "name", "track", "attrs", "t0")
+    __slots__ = ("_tel", "name", "track", "attrs", "t0", "_ann")
 
-    def __init__(self, tel: "Telemetry", name: str, track: str,
+    def __init__(self, tel: Optional["Telemetry"], name: str, track: str,
                  attrs: Dict[str, Any]):
         self._tel = tel
         self.name = name
         self.track = track
         self.attrs = attrs
         self.t0 = 0.0
+        self._ann = _Annotation(name, **attrs) if profiling() else None
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the span's work has run."""
+        self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
 
     def __enter__(self) -> "_SpanCtx":
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tel.span_at(self.name, self.t0, time.perf_counter(),
-                          track=self.track, clock="wall", **self.attrs)
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._tel is not None:
+            self._tel.span_at(self.name, self.t0, t1, track=self.track,
+                              clock="wall", **self.attrs)
 
 
 class _NullCtx:
@@ -134,6 +185,9 @@ class _NullCtx:
 
     def __init__(self) -> None:
         self.attrs: Dict[str, Any] = {}
+
+    def set(self, **attrs) -> None:
+        pass
 
     def __enter__(self) -> "_NullCtx":
         return self
@@ -163,12 +217,14 @@ class Telemetry:
 
     # ---- recording ----------------------------------------------------------
     def span(self, name: str, track: str = "main", **attrs):
-        """Wall-clock span context manager: ``with tel.span("x"): ...``."""
+        """Wall-clock span context manager: ``with tel.span("x"): ...``;
+        also in the profiler's trace while a session is active."""
         return _SpanCtx(self, name, track, attrs)
 
     def span_at(self, name: str, t0: float, t1: float, track: str = "main",
                 clock: str = "wall", **attrs) -> None:
-        """Record a span with explicit start/end (either clock)."""
+        """Record a span with explicit start/end (either clock); into
+        this recorder only, never the profiler's trace."""
         self.spans.append({"name": name, "t0": t0, "t1": t1,
                            "track": track, "clock": clock, "attrs": attrs})
 
@@ -349,11 +405,13 @@ class Telemetry:
 
 
 class _NoopTelemetry(Telemetry):
-    """Default recorder: every method returns immediately, records nothing.
+    """Default recorder: every method returns immediately, records nothing
+    — except ``span``, which still reaches an active profiler session.
 
-    Instrumented call sites check ``tel.enabled`` before computing attrs,
-    and even un-gated calls are a no-op — pinned traces stay bit-identical
-    (the ``risk_tau_s=None`` contract).
+    Instrumented call sites check ``tel.enabled`` (or :func:`active`,
+    for span attributes) before computing attrs, and even un-gated calls
+    are a no-op — pinned traces stay bit-identical (the
+    ``risk_tau_s=None`` contract).
     """
 
     enabled = False
@@ -362,7 +420,8 @@ class _NoopTelemetry(Telemetry):
         super().__init__()
 
     def span(self, name, track="main", **attrs):
-        return _NULL_CTX
+        return _SpanCtx(None, name, track, attrs) if profiling() \
+            else _NULL_CTX
 
     def span_at(self, *a, **k) -> None:
         pass

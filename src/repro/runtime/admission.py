@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -80,6 +81,8 @@ class AdmissionQueue:
         self._heap: List[Tuple[int, float, int, Request]] = []
 
     def push(self, req: Request) -> None:
+        """Queue ``req``, stamping its ``t_queued`` on the host clock."""
+        req.t_queued = time.perf_counter()
         heapq.heappush(self._heap,
                        (req.priority, req.arrival, req.rid, req))
         tel = telemetry.get()
@@ -120,9 +123,6 @@ class LatencyWindow:
             return
         lat = (req.t_done - req.arrival) / len(req.out)
         self._samples.append(lat)
-        tel = telemetry.get()
-        if tel.enabled:
-            tel.observe("serve.latency_per_token_s", lat)
         if len(self._samples) > self.window:
             del self._samples[:-self.window]
 

@@ -62,6 +62,7 @@ class Request:
     t_admit: Optional[float] = None  # when a slot/batch accepted it
     t_first: Optional[float] = None  # first decoded token emitted
     t_done: Optional[float] = None   # last token emitted (slot freed)
+    t_queued: Optional[float] = None  # host clock (perf_counter) at queue push
 
 
 @dataclasses.dataclass
@@ -453,7 +454,12 @@ class ContinuousServeLoop:
               extras: Optional[Dict[str, Any]] = None) -> Optional[int]:
         """Prefill ``req`` into a free slot; returns the slot index or
         None when the batch is full.  Runs between decode steps — the
-        other lanes' in-flight state is untouched."""
+        other lanes' in-flight state is untouched.
+
+        Spans: ``serve.admit`` (rid, plen, bucket, slot, and queued_us,
+        the host time since the queue's push), around
+        ``serve.admit.prepare`` (padding and the host-to-device
+        transfers) and ``serve.admit.dispatch`` (the jitted admit)."""
         slot = next((i for i in range(self.slots)
                      if self._reqs[i] is None), None)
         if slot is None:
@@ -462,31 +468,37 @@ class ContinuousServeLoop:
         plen = len(prompt)
         assert 0 < plen <= self._size, \
             f"prompt ({plen}) must fit the decode buffer ({self._size})"
-        self._ensure_states()
         bucket = self.bucket(plen)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :plen] = prompt
-        batch = self._replicated({"tokens": jnp.asarray(tokens),
-                                  **(extras or {})})
-        fn = self._admit_fn(bucket)
-        self._states, self._cur = fn(self.params, self._states, self._cur,
-                                     batch, jnp.int32(plen),
-                                     jnp.int32(slot))
-        self._reqs[slot] = req
-        self._plen[slot] = plen
-        self._t[slot] = 0
-        self._max_new[slot] = req.max_new_tokens
+        tel = telemetry.get()
+        attrs = {}
+        if telemetry.active():
+            attrs = {"rid": int(req.rid), "plen": plen, "bucket": bucket,
+                     "slot": slot}
+            if req.t_queued is not None:
+                attrs["queued_us"] = (time.perf_counter()
+                                      - req.t_queued) * 1e6
+        with tel.span("serve.admit", track="serve", **attrs):
+            with tel.span("serve.admit.prepare", track="serve"):
+                self._ensure_states()
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :plen] = prompt
+                batch = self._replicated({"tokens": jnp.asarray(tokens),
+                                          **(extras or {})})
+                length, lane = jnp.int32(plen), jnp.int32(slot)
+            with tel.span("serve.admit.dispatch", track="serve"):
+                fn = self._admit_fn(bucket)
+                self._states, self._cur = fn(self.params, self._states,
+                                             self._cur, batch, length, lane)
+            self._reqs[slot] = req
+            self._plen[slot] = plen
+            self._t[slot] = 0
+            self._max_new[slot] = req.max_new_tokens
         self.stats.prefill_tokens += plen
         self.stats.admitted += 1
         if now is not None:
             req.t_admit = now
-        tel = telemetry.get()
         if tel.enabled:
             tel.count("serve.admitted")
-            tel.gauge("serve.slot_occupancy", self.active / self.slots,
-                      t=now)
-            if now is not None:
-                tel.observe("serve.queue_wait_s", now - req.arrival)
         return slot
 
     def _free(self, slot: int) -> None:
@@ -502,44 +514,49 @@ class ContinuousServeLoop:
     # ---- decode ------------------------------------------------------------
     def decode_step(self, now: Optional[float] = None) -> int:
         """One token for every occupied slot; returns how many lanes
-        decoded.  The step boundary is the gang's control point."""
+        decoded.  The step boundary is the gang's control point.
+
+        Spans: ``serve.decode_step`` (lanes; ctx_tokens, the context the
+        lanes' attention covers; kv_positions, the positions it reads),
+        around ``serve.decode.sync`` (the fetch of the previous step's
+        tokens), ``serve.decode.dispatch`` (positions and the step
+        program) and ``serve.decode.select`` (the argmax and the lane
+        bookkeeping).  Between the end of the sync and the end of the
+        dispatch the device has nothing queued."""
         act = [i for i in range(self.slots) if self._reqs[i] is not None]
         if not act:
             return 0
         tel = telemetry.get()
-        t_step = time.perf_counter() if tel.enabled else 0.0
-        cur = np.asarray(self._cur)
-        for i in act:
-            r = self._reqs[i]
-            if not r.out and now is not None:
-                r.t_first = now
-                if tel.enabled:
-                    tel.observe("serve.ttft_s", now - r.arrival)
-            r.out.append(int(cur[i]))
-        pos = np.where(self._occ(), self._plen + self._t, 0)
-        pos = jnp.asarray(pos[:, None].astype(np.int32))
-        logits, self._states = self._serve(self.params, self._states,
-                                           self._cur[:, None], pos)
-        self._cur = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        for i in act:
-            self._t[i] += 1
-            if self._t[i] >= self._max_new[i]:
+        attrs = {}
+        if telemetry.active():
+            attrs = {"lanes": len(act),
+                     "ctx_tokens": int(np.sum(self._plen[act] + self._t[act],
+                                              dtype=np.int64)) + len(act),
+                     "kv_positions": self.slots * self._size}
+        with tel.span("serve.decode_step", track="serve", **attrs):
+            with tel.span("serve.decode.sync", track="serve"):
+                cur = np.asarray(self._cur)
+            for i in act:
                 r = self._reqs[i]
-                if now is not None:
-                    r.t_done = now
-                    if tel.enabled and r.t_first is not None and r.out:
-                        tel.observe("serve.per_token_s",
-                                    (now - r.t_first)
-                                    / max(1, len(r.out)))
-                self._free(i)
+                if not r.out and now is not None:
+                    r.t_first = now
+                r.out.append(int(cur[i]))
+            with tel.span("serve.decode.dispatch", track="serve"):
+                pos = np.where(self._occ(), self._plen + self._t, 0)
+                pos = jnp.asarray(pos[:, None].astype(np.int32))
+                logits, self._states = self._serve(
+                    self.params, self._states, self._cur[:, None], pos)
+            with tel.span("serve.decode.select", track="serve"):
+                self._cur = jnp.argmax(logits[:, 0],
+                                       axis=-1).astype(jnp.int32)
+                for i in act:
+                    self._t[i] += 1
+                    if self._t[i] >= self._max_new[i]:
+                        if now is not None:
+                            self._reqs[i].t_done = now
+                        self._free(i)
         if tel.enabled:
             tel.count("serve.decoded_tokens", len(act))
-            tel.gauge("serve.slot_occupancy", self.active / self.slots,
-                      t=now)
-            tel.span_at("serve.decode_step", t_step,
-                        time.perf_counter(), track="serve",
-                        clock="wall", lanes=len(act),
-                        occupancy=self.active / self.slots)
         self.stats.decoded_tokens += len(act)
         self.stats.steps += 1
         return len(act)

@@ -290,3 +290,120 @@ def test_trace_result_straggler_migrations_defaults_to_zero():
     res = _churn_run()
     # pure-simulator gangs have no stragglers: field exists, stays 0
     assert res.straggler_migrations == 0
+
+
+# ---- one span API, two sinks: the recorder and the profiler's trace ---------
+
+def _profiled_spans(trace_dir, prefixes):
+    """(name, attrs) of the host events under ``prefixes`` in the newest
+    profiler trace under ``trace_dir``, in start order."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                      "*.xplane.pb")), key=os.path.getmtime)
+    evs = [(e.start_ns, e.name, dict(e.stats))
+           for p in ProfileData.from_file(path).planes
+           if p.name.startswith("/host:") for line in p.lines
+           for e in line.events if e.name.startswith(prefixes)]
+    return [(n, a) for _, n, a in sorted(evs, key=lambda e: e[0])]
+
+
+def _small_serve_loop():
+    import jax
+
+    from repro.configs.registry import reduced_config
+    from repro.models import transformer as tf
+    from repro.runtime.serve_loop import ContinuousServeLoop
+
+    cfg = reduced_config("llama3.2-1b").with_(n_layers=1, vocab=64)
+    params = jax.jit(lambda k: tf.init_params(k, cfg))(
+        jax.random.PRNGKey(0))
+    return ContinuousServeLoop(cfg, params, slots=4, max_len=32)
+
+
+def _serve_a_little(loop):
+    from repro.runtime.serve_loop import Request
+    for rid, n in ((0, 5), (1, 11)):
+        loop.admit(Request(rid=rid, prompt=np.arange(n, dtype=np.int32),
+                           max_new_tokens=4))
+    for _ in range(3):
+        loop.decode_step()
+
+
+SERVE_SPANS = {"serve.admit", "serve.admit.prepare", "serve.admit.dispatch",
+               "serve.decode_step", "serve.decode.sync",
+               "serve.decode.dispatch", "serve.decode.select"}
+
+
+def test_serve_path_builds_no_annotation_when_nothing_records(monkeypatch,
+                                                               tmp_path):
+    import jax
+
+    class Counting(jax.profiler.TraceAnnotation):
+        made = 0
+
+        def __init__(self, name, **attrs):
+            Counting.made += 1
+            super().__init__(name, **attrs)
+
+    loop = _small_serve_loop()
+    monkeypatch.setattr(telemetry, "_Annotation", Counting)
+    assert not telemetry.active()
+    _serve_a_little(loop)
+    assert Counting.made == 0
+    assert telemetry.get().spans == []
+    # the control: with a profiler session the same path builds them
+    with jax.profiler.trace(str(tmp_path)):
+        assert telemetry.profiling() and telemetry.active()
+        loop.decode_step()
+    assert Counting.made == 4
+
+
+def test_live_recorder_records_serve_spans_in_memory():
+    loop = _small_serve_loop()
+    with telemetry.recording() as tel:
+        _serve_a_little(loop)
+    assert {s["name"] for s in tel.spans} == SERVE_SPANS
+    assert all(s["track"] == "serve" and s["clock"] == "wall"
+               for s in tel.spans)
+    steps = [s["attrs"] for s in tel.spans
+             if s["name"] == "serve.decode_step"]
+    assert [(a["lanes"], a["ctx_tokens"], a["kv_positions"])
+            for a in steps] == [(2, 18, 128), (2, 20, 128), (2, 22, 128)]
+    admit = next(s["attrs"] for s in tel.spans if s["name"] == "serve.admit")
+    # admitted without passing through a queue: no queue wait
+    assert admit == {"rid": 0, "plen": 5, "bucket": 8, "slot": 0}
+    assert tel.counters["serve.decoded_tokens"] == 6
+    # the virtual-clock histograms and the occupancy gauge are gone
+    assert not any(k.startswith("serve.") for k in tel.histograms)
+    assert "serve.slot_occupancy" not in tel.gauges
+    cats = {e.get("cat") for e in tel.to_chrome_trace()["traceEvents"]}
+    assert "serve" in cats
+
+
+@pytest.mark.parametrize("live", [False, True], ids=["noop", "recorder"])
+def test_spans_reach_the_profiler_trace_with_late_attributes(tmp_path, live):
+    import jax
+
+    from repro.checkpoint.manager import CheckpointManager
+
+    mgr = CheckpointManager(str(tmp_path / "ckpt"), job_id="j")
+    state = {"w": np.arange(8, dtype=np.float32)}
+    tel = telemetry.enable() if live else telemetry.get()
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        with tel.span("x.outer", track="t", a=1):
+            with tel.span("x.inner", track="t") as sp:
+                sp.set(b=2.5)
+        mgr.save(3, state)
+        mgr.restore(3)
+    got = _profiled_spans(str(tmp_path / "trace"), ("x.", "ckpt."))
+    assert got == [("x.outer", {"a": 1}), ("x.inner", {"b": 2.5}),
+                   ("ckpt.save", {"step": 3, "kind": "full", "bytes": 32,
+                                  "full_bytes": 32}),
+                   ("ckpt.restore", {"step": 3, "kind": "full"})]
+    # the recorder keeps the same spans and attributes in memory
+    assert [(s["name"], s["attrs"]) for s in tel.spans] == (
+        [("x.inner", {"b": 2.5}), ("x.outer", {"a": 1}),
+         got[2], got[3]] if live else [])
