@@ -168,30 +168,27 @@ def init_kv_cache(cfg, batch, max_len, dtype, window: int = 0):
     }
 
 
-def decode_attention(params, x, cache, cfg, positions, *, window=0,
-                     kv_x=None, use_rope=True):
-    """One-token decode step: append to cache, attend over it.
-
-    x: (B,1,d); positions: (B,1) absolute position of the new token.
-    Returns (out, new_cache).
-    """
+def _decode_qkv(params, x, cfg, positions, use_rope):
+    """The new token's query and its key/value row, each (B,1,heads,hd)."""
     hd = cfg.hd()
     q = _split_heads(matmul(x, params["wq"]), cfg.n_heads, hd)
-    if kv_x is not None:
-        # Cross-attention: cache holds the (static) encoder/image K/V.
-        out = sdpa(q, cache["k"], cache["v"])
-        out = out.reshape(*x.shape[:-1], cfg.n_heads * hd)
-        return matmul_rp(out, params["wo"], cfg), cache
     k_new = _split_heads(matmul(x, params["wk"]), cfg.n_kv_heads, hd)
     v_new = _split_heads(matmul(x, params["wv"]), cfg.n_kv_heads, hd)
     if use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k_new = apply_rope(k_new, positions, cfg.rope_theta)
-    size = cache["k"].shape[1]
-    slot = (positions[:, 0] % size) if window else positions[:, 0]
-    bidx = jnp.arange(x.shape[0])
-    k = cache["k"].at[bidx, slot].set(k_new[:, 0])
-    v = cache["v"].at[bidx, slot].set(v_new[:, 0])
+    return q, k_new, v_new
+
+
+def _decode_slot(positions, size, window):
+    """Cache row each lane's new token goes to (ring buffer with a window)."""
+    return (positions[:, 0] % size) if window else positions[:, 0]
+
+
+def _decode_attend(params, q, k, v, cfg, positions, window):
+    """Attend the new token over one layer's cache k, v (B,size,KV,hd),
+    which already holds its row."""
+    size = k.shape[1]
     # Valid-position mask: ring buffer slot j holds a token iff it has been
     # written and (windowed) is within ``window`` of the current position.
     pos = positions[:, 0][:, None]                      # (B,1)
@@ -205,5 +202,55 @@ def decode_attention(params, x, cache, cfg, positions, *, window=0,
         valid = j <= pos
     mask = valid[:, None, None, None, :]                # (B,KV,G,1,size)
     out = sdpa(q, k, v, mask=mask)
-    out = out.reshape(*x.shape[:-1], cfg.n_heads * hd)
-    return matmul_rp(out, params["wo"], cfg), {"k": k, "v": v}
+    out = out.reshape(*q.shape[:2], cfg.n_heads * cfg.hd())
+    return matmul_rp(out, params["wo"], cfg)
+
+
+def decode_attention(params, x, cache, cfg, positions, *, window=0,
+                     kv_x=None, use_rope=True):
+    """One-token decode step: append to cache, attend over it.
+
+    x: (B,1,d); positions: (B,1) absolute position of the new token.
+    Returns (out, new_cache).
+    """
+    if kv_x is not None:
+        # Cross-attention: cache holds the (static) encoder/image K/V.
+        hd = cfg.hd()
+        q = _split_heads(matmul(x, params["wq"]), cfg.n_heads, hd)
+        out = sdpa(q, cache["k"], cache["v"])
+        out = out.reshape(*x.shape[:-1], cfg.n_heads * hd)
+        return matmul_rp(out, params["wo"], cfg), cache
+    q, k_new, v_new = _decode_qkv(params, x, cfg, positions, use_rope)
+    slot = _decode_slot(positions, cache["k"].shape[1], window)
+    bidx = jnp.arange(x.shape[0])
+    k = cache["k"].at[bidx, slot].set(k_new[:, 0])
+    v = cache["v"].at[bidx, slot].set(v_new[:, 0])
+    return (_decode_attend(params, q, k, v, cfg, positions, window),
+            {"k": k, "v": v})
+
+
+def decode_attention_stacked(params, x, stack, layer, cfg, positions, *,
+                             window=0, use_rope=True):
+    """``decode_attention`` for layer ``layer`` of a stacked cache.
+
+    stack: {"k","v"} of (L,B,size,KV,hd).  Each lane's new row is written
+    at ``[layer, b, slot]`` of the whole stack, in place when the stack
+    rides in a loop carry, and the token attends over ``stack[layer]``:
+    no layer of the cache is copied out and written back.  Without a
+    window, positions must lie inside the cache.
+    Returns (out, new_stack).
+    """
+    q, k_new, v_new = _decode_qkv(params, x, cfg, positions, use_rope)
+    slot = _decode_slot(positions, stack["k"].shape[2], window)
+    k, v = stack["k"], stack["v"]
+    # One dynamic-update-slice per lane rather than one scatter: a TPU
+    # keeps a (..., KV, 64) bf16 cache with the position axis minor, a
+    # scatter of (KV, hd) rows wants it row-major, and the compiler then
+    # converts the whole carried cache to that layout and back.
+    for b in range(x.shape[0]):
+        at = (layer, b, slot[b], 0, 0)
+        k = jax.lax.dynamic_update_slice(k, k_new[b:b + 1, None], at)
+        v = jax.lax.dynamic_update_slice(v, v_new[b:b + 1, None], at)
+    return (_decode_attend(params, q, k[layer], v[layer], cfg, positions,
+                           window),
+            {"k": k, "v": v})

@@ -157,13 +157,30 @@ def apply_block_seq(kind: str, p, x, cfg, ctx) -> Tuple[jnp.ndarray,
 # ---------------------------------------------------------------------------
 # Per-kind block apply — single-token decode
 # ---------------------------------------------------------------------------
-def apply_block_decode(kind: str, p, x, state, cfg, ctx):
-    """x: (B,1,d) -> (x', new_state)."""
+#: decode-state leaves a block writes one row of per token: the decode
+#: scan carries them whole and writes the rows in place
+ROW_WRITTEN = {cb.ATTN: ("k", "v"), cb.MOE: ("k", "v"),
+               cb.SHARED_ATTN: ("k", "v"), cb.ENCDEC: ("k", "v")}
+#: decode-state leaves a block only reads (cross-attention K/V)
+READ_ONLY = {cb.CROSS_ATTN: ("k", "v"), cb.ENCDEC: ("xk", "xv")}
+#: block kinds whose whole state is rewritten every token
+RECURRENT = (cb.MAMBA, cb.MLSTM, cb.SLSTM)
+
+
+def apply_block_decode(kind: str, p, x, rows, fixed, recur, layer, cfg,
+                       ctx):
+    """x: (B,1,d) -> (x', new_rows, new_recur).
+
+    The block's state comes split by how decoding uses it: ``rows`` the
+    whole stacked (L,...) leaves of ``ROW_WRITTEN``, written at
+    ``layer``; ``fixed`` this layer's ``READ_ONLY`` leaves; ``recur``
+    this layer's recurrent state.
+    """
     pos = ctx["positions"]          # (B,1) absolute positions
     if kind in (cb.ATTN, cb.SHARED_ATTN, cb.MOE):
-        h, state = attn.decode_attention(
-            p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps), state, cfg, pos,
-            window=ctx.get("window", 0))
+        h, rows = attn.decode_attention_stacked(
+            p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps), rows, layer, cfg,
+            pos, window=ctx.get("window", 0))
         x = x + h
         if kind == cb.MOE:
             h, _ = moe_mod.moe_ffn(p["moe"],
@@ -171,41 +188,40 @@ def apply_block_decode(kind: str, p, x, state, cfg, ctx):
         else:
             h = mlp(p["mlp"], rms_norm(p["ln2"], x, cfg.norm_eps), cfg.act,
                 cfg)
-        return x + h, state
+        return x + h, rows, recur
     if kind == cb.CROSS_ATTN:
         h, _ = attn.decode_attention(
-            p["xattn"], rms_norm(p["ln1"], x, cfg.norm_eps), state, cfg, pos,
+            p["xattn"], rms_norm(p["ln1"], x, cfg.norm_eps), fixed, cfg, pos,
             kv_x=True, use_rope=False)
         x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * h
         h = mlp(p["mlp"], rms_norm(p["ln2"], x, cfg.norm_eps), cfg.act,
                 cfg)
-        return x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * h, state
+        return x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * h, rows, recur
     if kind == cb.ENCDEC:
-        self_cache = {"k": state["k"], "v": state["v"]}
-        h, self_cache = attn.decode_attention(
-            p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps), self_cache, cfg,
+        h, rows = attn.decode_attention_stacked(
+            p["attn"], rms_norm(p["ln1"], x, cfg.norm_eps), rows, layer, cfg,
             pos)
         x = x + h
         h, _ = attn.decode_attention(
             p["xattn"], rms_norm(p["lnx"], x, cfg.norm_eps),
-            {"k": state["xk"], "v": state["xv"]}, cfg, pos, kv_x=True,
+            {"k": fixed["xk"], "v": fixed["xv"]}, cfg, pos, kv_x=True,
             use_rope=False)
         x = x + h
         h = mlp(p["mlp"], rms_norm(p["ln2"], x, cfg.norm_eps), cfg.act,
                 cfg)
-        return x + h, {**self_cache, "xk": state["xk"], "xv": state["xv"]}
+        return x + h, rows, recur
     if kind == cb.MAMBA:
-        h, state = ssm_mod.mamba_decode(
-            p["mamba"], rms_norm(p["ln1"], x, cfg.norm_eps), state, cfg)
-        return x + h, state
+        h, recur = ssm_mod.mamba_decode(
+            p["mamba"], rms_norm(p["ln1"], x, cfg.norm_eps), recur, cfg)
+        return x + h, rows, recur
     if kind == cb.MLSTM:
-        h, state = xlstm_mod.mlstm_decode(
-            p["mlstm"], rms_norm(p["ln1"], x, cfg.norm_eps), state, cfg)
-        return x + h, state
+        h, recur = xlstm_mod.mlstm_decode(
+            p["mlstm"], rms_norm(p["ln1"], x, cfg.norm_eps), recur, cfg)
+        return x + h, rows, recur
     if kind == cb.SLSTM:
-        h, state = xlstm_mod.slstm_decode(
-            p["slstm"], rms_norm(p["ln1"], x, cfg.norm_eps), state, cfg)
-        return x + h, state
+        h, recur = xlstm_mod.slstm_decode(
+            p["slstm"], rms_norm(p["ln1"], x, cfg.norm_eps), recur, cfg)
+        return x + h, rows, recur
     raise ValueError(kind)
 
 
@@ -362,36 +378,53 @@ def decode_step(params, tokens, states, positions, cfg,
 
     states: output of ``init_decode_state`` (possibly filled by prefill).
     Returns (logits (B,1,V), new_states).
+
+    The layer scan carries the ``ROW_WRITTEN`` leaves whole and each
+    layer writes its lanes' new rows into them in place; ``READ_ONLY``
+    leaves go in as ``xs`` and come back as given; only the recurrent
+    states go through the scan's ``ys``.
     """
     ctx = dict(ctx or {})
     ctx["positions"] = positions
     x = jnp.take(params["embed"], tokens, axis=0)
     period = cfg.period()
     scanned_params = tuple(p for p in params["blocks"] if p is not None)
-    scanned_states = tuple(states)
+    pick = lambda st, names: {n: st[n] for n in names} or None
+    rows = tuple(pick(st, ROW_WRITTEN.get(k, ()))
+                 for k, st in zip(period, states))
+    fixed = tuple(pick(st, READ_ONLY.get(k, ()))
+                  for k, st in zip(period, states))
+    recur = tuple(st if k in RECURRENT else None
+                  for k, st in zip(period, states))
 
-    def period_body(x, xs):
-        ps, sts = xs
+    def period_body(carry, xs):
+        x, rows = carry
+        layer, ps, fixed, recur = xs
         it = iter(ps)
-        new_sts = []
-        for kind, st in zip(period, sts):
+        new_rows, new_recur = [], []
+        for kind, r, f, s in zip(period, rows, fixed, recur):
             p = params["shared"] if kind == cb.SHARED_ATTN else next(it)
-            x, st2 = apply_block_decode(kind, p, x, st, cfg, ctx)
-            new_sts.append(st2)
-        return x, tuple(new_sts)
+            x, r, s = apply_block_decode(kind, p, x, r, f, s, layer, cfg,
+                                         ctx)
+            new_rows.append(r)
+            new_recur.append(s)
+        return (x, tuple(new_rows)), tuple(new_recur)
 
+    n_per = cfg.n_periods()
     if cfg.scan_layers:
-        x, new_states = jax.lax.scan(
-            period_body, x, (scanned_params, scanned_states))
+        (x, rows), recur = jax.lax.scan(
+            period_body, (x, rows),
+            (jnp.arange(n_per), scanned_params, fixed, recur))
     else:
         outs = []
-        for i in range(cfg.n_periods()):
-            ps = jax.tree.map(lambda a: a[i], scanned_params)
-            sts = jax.tree.map(lambda a: a[i], scanned_states)
-            x, st2 = period_body(x, (ps, sts))
+        for i in range(n_per):
+            sl = jax.tree.map(lambda a: a[i], (scanned_params, fixed, recur))
+            (x, rows), st2 = period_body((x, rows), (i, *sl))
             outs.append(st2)
-        new_states = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+        recur = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
+    new_states = [s if k in RECURRENT else {**(r or {}), **(f or {})}
+                  for k, r, f, s in zip(period, rows, fixed, recur)]
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    return matmul(x, head), list(new_states)
+    return matmul(x, head), new_states

@@ -7,6 +7,7 @@ VMEM than a kernel may use.  The topology is described inside a fixture
 (only the worker that runs this file loads the TPU compiler), and the
 persistent compilation cache is off around the compiles.
 """
+import math
 import os
 import re
 
@@ -110,3 +111,123 @@ def test_select_codec_with_ragged_chunk_rows(one_chip, frac):
     assert _compile(
         lambda v: ops.select_codec(v, frac=frac, use_kernel=True),
         one_chip, ((n,), jnp.float32)) == {"chunk_select"}
+
+
+# ---------------------------------------------------------------------------
+# Whole-cache traffic of the decode step, read from the optimized HLO
+# ---------------------------------------------------------------------------
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+                "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8}
+_MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+
+
+def _computations(hlo):
+    """name -> instruction lines of each computation, and the entry's name."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) \(", line)
+        if head:
+            cur = comps.setdefault(head.group(2), [])
+            entry = head.group(2) if head.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps, entry
+
+
+def _instrs(lines):
+    """name -> (dtype, dims, opcode, operands and attributes, is_root);
+    a tuple-shaped instruction reads as dtype "tuple"."""
+    out = {}
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)", line)
+        if not m:
+            continue
+        name, rhs = m.groups()
+        if rhs.startswith("("):
+            depth = 0
+            for end, c in enumerate(rhs):
+                depth += {"(": 1, ")": -1}.get(c, 0)
+                if depth == 0:
+                    break
+            dt, dims, rhs = "tuple", "", rhs[end + 2:]
+        else:
+            shape, _, rhs = rhs.partition(" ")
+            dt, _, dims = shape.partition("[")
+            dims = dims.split("]")[0]
+        op, _, rest = rhs.partition("(")
+        out[name] = (dt, tuple(int(d) for d in dims.split(",") if d), op,
+                     rest, line.lstrip().startswith("ROOT"))
+    return out
+
+
+def cache_bytes_moved(hlo, cache_shapes):
+    """Bytes written per run of the program by copies, dynamic slices and
+    dynamic updates (the update's bytes) of an array shaped as one of
+    ``cache_shapes`` (leading 1s dropped).  Only ops the device runs as
+    their own kernel count, or fusions rooted at one; an op in a loop
+    counts once per trip (the trip count read from the loop's condition)."""
+    comps, entry = _computations(hlo)
+    tables = {name: _instrs(lines) for name, lines in comps.items()}
+    want = {tuple(s) for s in cache_shapes}
+
+    def squeeze(shape):
+        while shape and shape[0] == 1:
+            shape = shape[1:]
+        return shape
+
+    def walk(comp, trips):
+        total = 0
+        table = tables[comp]
+        for dt, shape, op, rest, _ in table.values():
+            if op == "while":
+                body = re.search(r"body=%([\w.-]+)", rest).group(1)
+                cond = re.search(r"condition=%([\w.-]+)", rest).group(1)
+                n = max(int(c) for c in re.findall(
+                    r"s32\[\]\S* constant\((\d+)\)", "\n".join(comps[cond])))
+                total += walk(body, trips * n)
+                continue
+            inner = table
+            if op == "fusion":
+                inner = tables[re.search(r"calls=%([\w.-]+)", rest).group(1)]
+                dt, shape, op, rest, _ = next(v for v in inner.values()
+                                              if v[4])
+            if op not in _MOVES:
+                continue
+            if op == "dynamic-update-slice":
+                dt, shape = inner[re.findall(r"%([\w.-]+)", rest)[1]][:2]
+            if squeeze(shape) in want:
+                total += trips * _DTYPE_BYTES[dt] * math.prod(shape)
+        return total
+
+    return walk(entry, 1)
+
+
+def test_serve_step_moves_at_most_two_whole_caches(one_chip):
+    """The decode step at granite width, 28 slots of 4096: copies, slices
+    and updates of cache-shaped arrays write at most two whole caches a
+    step.  (Slicing each layer out of the scan's ``xs``, a scatter copy,
+    layout copies and re-stacking through ``ys`` wrote four.)"""
+    from repro.models import model as M
+    from repro.models import transformer as tf
+    cfg = get_config("granite-moe-1b-a400m").with_(
+        capacity_factor=4.0, use_pallas_kernels=True)
+    slots, max_len = 28, 4096
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(lambda k: tf.init_params(k, cfg),
+                                   jax.random.PRNGKey(0)))
+    states = shaped(jax.eval_shape(lambda: tf.init_decode_state(
+        cfg, slots, max_len, cfg.param_dtype())))
+    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    hlo = jax.jit(M.make_serve_step(cfg)).lower(
+        params, states, tok, tok).compile().as_text()
+    layer = (slots, max_len, cfg.n_kv_heads, cfg.hd())
+    whole = sum(math.prod(a.shape) * a.dtype.itemsize
+                for a in jax.tree.leaves(states))
+    moved = cache_bytes_moved(hlo, [layer, (cfg.n_periods(),) + layer])
+    assert moved <= 2 * whole, f"{moved / whole:.2f} whole caches a step"
